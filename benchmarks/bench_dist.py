@@ -32,18 +32,10 @@ Run standalone (``python -m benchmarks.bench_dist``) this module forces
 measures whatever device count the process already has.  ``--skew``
 runs only the skew section (fast inner loop for re-balancer work).
 """
-import os
-import sys
-from functools import partial
-
-if "jax" not in sys.modules and "--xla_force_host_platform_device_count" \
-        not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=8"
-                               ).strip()
-
 import json
+import os
 import time
+from functools import partial
 
 import numpy as np
 
@@ -268,6 +260,15 @@ def record_baseline(path: str | None = None, quick: bool = True) -> dict:
 
 if __name__ == "__main__":
     import argparse
+
+    # eight virtual CPU devices for the sharded rows, set before the first
+    # device use; the flag touches only the CPU backend, so on a chip host
+    # the mesh is the chips
+    if "--xla_force_host_platform_device_count" not in os.environ.get(
+            "XLA_FLAGS", ""):
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + " --xla_force_host_platform_device_count=8").strip()
 
     ap = argparse.ArgumentParser(description="distributed join/compression "
                                              "scaling benchmark")

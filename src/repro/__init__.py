@@ -3,7 +3,9 @@
 x64 is enabled package-wide: join counts are exact int64 on device (the
 paper's benchmark outputs overflow int32 at Pokec/LiveJournal scale).
 Model code uses explicit bf16/f32 dtypes throughout, so the x64 default
-only affects the integer join/count paths.  Opt out with ``REPRO_X64=0``.
+only affects the integer join/count paths.  Pallas kernel bodies are
+traced with x64 off (``kernels/backend.py``): the TPU kernel compiler has
+no 64-bit types.  Opt out with ``REPRO_X64=0``.
 """
 import os as _os
 
@@ -11,20 +13,5 @@ import jax as _jax
 
 if _os.environ.get("REPRO_X64", "1") == "1":
     _jax.config.update("jax_enable_x64", True)
-
-if not hasattr(_jax, "shard_map"):
-    # jax >= 0.6 promotes shard_map to the top-level namespace and renames
-    # check_rep -> check_vma; older jax only has the experimental spelling.
-    # repro.dist and the multi-device tests target the new API, so bridge
-    # it here (importing any repro subpackage runs this first).
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=True,
-                   **kwargs):
-        return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma,
-                                 **kwargs)
-
-    _jax.shard_map = _shard_map
 
 __version__ = "1.0.0"

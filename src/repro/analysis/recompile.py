@@ -6,11 +6,14 @@ engine whose shape space is unbounded will "win" every microbenchmark
 and then compile forever in serving.  The executors bound their shape
 spaces deliberately:
 
-* **interior levels** (``VLFTJ._run``) pad partial chunks to the next
-  power of two with a floor of 8, so per static-arg combo the chunk
-  kernel sees at most ``log2(chunk_rows / 8) + 1`` distinct row counts;
+* **interior levels** (``VLFTJ._run``) bucket rows into power-of-two
+  candidate-width classes (``core.plan.width_classes``) and pad partial
+  chunks to the next power of two with a floor of 8, so per static-arg
+  combo and width class the chunk kernel sees at most
+  ``log2(chunk_rows / 8) + 1`` distinct row counts;
 * the **final level** AOT cache (``VLFTJ._final_level_call``, keyed on
-  ``(frontier.shape, count_only)``) sees the fixed counting window
+  ``(frontier.shape, width, count_only)``) sees, per width class, the
+  fixed counting window
   (``chunk_rows`` rows), one expansion cap per paging configuration
   (``ResultCursor`` pads chunks to ``min(chunk_rows, page_rows)``), and
   the dense-final-level single-row probe;
@@ -32,13 +35,16 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-from ..core.plan import GraphStats, JoinPlan, executor_geometry
+from ..core.plan import (GraphStats, JoinPlan, executor_geometry,
+                         width_classes)
 from .findings import Finding
 
 #: default cap on statically-enumerated compile cache keys per plan.  A
-#: 7-level vlftj plan with mixed layouts lands around 4e2 keys; only a
-#: pathological geometry (or an unbounded paging dimension) crosses this.
-DEFAULT_RECOMPILE_BUDGET = 1024
+#: 7-level vlftj plan with mixed layouts over seven width classes lands
+#: around 2.7e3 keys (most never reached: a run compiles the shapes its
+#: frontier sizes hit); only a pathological geometry (or an unbounded
+#: paging dimension) crosses this.
+DEFAULT_RECOMPILE_BUDGET = 4096
 
 #: interior-level kernel variants the executor may bucket rows into:
 #: tile-probe and bsearch-probe always; +1 bitset-probe when the level's
@@ -126,14 +132,20 @@ def audit_recompilation(plan: JoinPlan, stats: GraphStats | None = None,
         return RecompileAudit(plan.engine, (), 0, 0, 0, budget, 0)
 
     if stats is not None:
-        _, chunk = executor_geometry(stats.max_degree, chunk_rows,
-                                     elem_budget)
+        # one row-shape ladder per width class: a narrower class takes
+        # more rows per chunk under the same element budget
+        full, _ = executor_geometry(stats.max_degree, chunk_rows,
+                                    elem_budget)
+        ladders = [chunk_shape_count(executor_geometry(
+            w, chunk_rows, elem_budget, width=w)[1])
+            for w in width_classes(full)]
     else:
         chunk = chunk_rows
-    if chunk < 1:
-        unbounded.append(f"chunk_rows={chunk} (< 1: no chunking bound)")
-        chunk = 1
-    shapes = chunk_shape_count(chunk)
+        if chunk < 1:
+            unbounded.append(f"chunk_rows={chunk} (< 1: no chunking bound)")
+            chunk = 1
+        ladders = [chunk_shape_count(chunk)]
+    shapes = sum(ladders)
 
     per_level: list[tuple[str, int]] = []
     final = 0
@@ -155,8 +167,9 @@ def audit_recompilation(plan: JoinPlan, stats: GraphStats | None = None,
             modes = _BASE_MODES
             if i < len(layouts) and layouts[i] in ("bitset", "mixed"):
                 modes += 1
-            # static-arg combos (probe modes) x padded row shapes x
-            # count_only specialization of the shared expand kernel
+            # static-arg combos (probe modes) x padded row shapes of
+            # every width class x count_only specialization of the
+            # shared expand kernel
             keys = modes * shapes * 2
             label = gao[i] if i < len(gao) else f"level{i}"
             per_level.append((label, keys))
@@ -172,7 +185,7 @@ def audit_recompilation(plan: JoinPlan, stats: GraphStats | None = None,
                 caps = 0
             else:
                 caps = max(0, int(paging_configs))
-            final = 2 * (2 + caps)
+            final = 2 * (2 + caps) * len(ladders)
     if plan.engine in ("yannakakis", "hybrid"):
         # SpMV tree passes: shapes fixed by the graph (n_nodes), one
         # up+down compile pair per tree edge, bounded by the variable

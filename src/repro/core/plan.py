@@ -43,6 +43,23 @@ def executor_geometry(max_degree: int, chunk_rows: int = 8192,
     return width, chunk
 
 
+#: narrowest candidate tile of the vectorized executor.  Rows are bucketed
+#: by probe-segment degree into power-of-two width classes from here up to
+#: the padded max degree, so a row of degree 20 is not padded to a hub's
+#: width (on soc-Slashdot0811 that cuts the padded lanes about 50x).
+MIN_WIDTH = 32
+
+
+def width_classes(width: int) -> tuple[int, ...]:
+    """Every candidate-tile width the executor may dispatch at, for a
+    graph whose full padded width is ``width``."""
+    out, w = [], min(MIN_WIDTH, width)
+    while w < width:
+        out.append(w)
+        w *= 2
+    return tuple(out) + (width,)
+
+
 @dataclass(frozen=True)
 class LevelPlan:
     """Static per-level constraint sets (indices into frontier columns).

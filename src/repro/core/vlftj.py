@@ -32,8 +32,8 @@ import numpy as np
 
 from ..kernels import ops as kops
 from .device_graph import GraphDB
-from .plan import (GraphStats, JoinPlan, LevelPlan, compile_levels,
-                   executor_geometry)
+from .plan import (MIN_WIDTH, GraphStats, JoinPlan, LevelPlan,
+                   compile_levels, executor_geometry)
 from .query import Query
 
 #: backward-compatible alias — the per-level compiler now lives in
@@ -214,8 +214,10 @@ class VLFTJ:
         lv = plan.level_layouts
         self.level_layouts = (lv if len(lv) == len(self.plan)
                               else ("array",) * len(self.plan))
-        # keep chunk x width under the element budget
+        # keep chunk x width under the element budget; a narrower width
+        # class takes more rows per chunk under the same two caps
         self.chunk_rows = self._chunk_cap
+        self._geometry = (chunk_rows, elem_budget)
         # the unified stats namespace (docs/OBSERVABILITY.md): scalar
         # counters plus per-GAO-level observations — level_rows maps
         # level -> observed frontier cardinality after it binds (the
@@ -305,6 +307,32 @@ class VLFTJ:
         if (~tile).any():
             out.append((frontier[~tile], mult[~tile], "bsearch"))
         return out
+
+    def row_widths(self, frontier: np.ndarray, level: int = -1) -> np.ndarray:
+        """Per-row candidate-tile width at ``level``: the row's probe
+        segment (its smallest bound adjacency) rounded up to a power of
+        two, between :data:`MIN_WIDTH` and the graph's padded width."""
+        lp = self.plan[level]
+        deg = self.gdb.csr.degrees[
+            frontier[:, list(lp.edge_sources)]].min(axis=1)
+        w = 1 << np.ceil(np.log2(np.maximum(deg, 1))).astype(np.int64)
+        return np.minimum(np.maximum(w, MIN_WIDTH), self.width)
+
+    def _width_split(self, frontier, mult, level: int):
+        """Split a bucket into ``(frontier, mult, width, chunk_rows)`` runs
+        of one width class each; each class keeps ``chunk x width`` under
+        the element budget."""
+        if frontier.shape[0] == 0:
+            return
+        widths = self.row_widths(frontier, level)
+        order = np.argsort(widths, kind="stable")
+        widths = widths[order]
+        cuts = np.flatnonzero(np.diff(widths)) + 1
+        for s, e in zip(np.r_[0, cuts], np.r_[cuts, len(order)]):
+            w = int(widths[s])
+            _, chunk = executor_geometry(w, *self._geometry, width=w)
+            idx = order[s:e]
+            yield frontier[idx], mult[idx], w, chunk
 
     # -- main loop -----------------------------------------------------------
     def _run(self, count_only: bool = True, frontier: np.ndarray | None = None,
@@ -396,15 +424,18 @@ class VLFTJ:
             for gfrontier, _, mode in groups:
                 paths[mode] = paths.get(mode, 0) + int(gfrontier.shape[0])
             new_rows, new_vals, new_mult = [], [], []
-            for gfrontier, gmult, mode in groups:
-                for s in range(0, gfrontier.shape[0], self.chunk_rows):
-                    e = min(gfrontier.shape[0], s + self.chunk_rows)
+            runs = [(f, m, mode, width, chunk)
+                    for gf, gm, mode in groups
+                    for f, m, width, chunk in self._width_split(gf, gm, level)]
+            for gfrontier, gmult, mode, width, chunk_rows in runs:
+                for s in range(0, gfrontier.shape[0], chunk_rows):
+                    e = min(gfrontier.shape[0], s + chunk_rows)
                     # pad a partial chunk only to the next power of two:
                     # kernel cost tracks live rows (a 100-row tail no
                     # longer dispatches a full chunk_rows kernel) while
                     # the jit cache stays bounded at log2(chunk_rows)
-                    # shapes per static-arg combo
-                    crows = min(self.chunk_rows,
+                    # shapes per static-arg combo and width class
+                    crows = min(chunk_rows,
                                 max(8, 1 << (e - s - 1).bit_length()))
                     pad = crows - (e - s)
                     fchunk = np.pad(gfrontier[s:e], ((0, pad), (0, 0)))
@@ -415,7 +446,7 @@ class VLFTJ:
                             jnp.asarray(mchunk), jnp.asarray(rv))
                     kw = dict(probe_cols=lp.edge_sources,
                               n_unary=len(bitmaps), lower_cols=lp.lower,
-                              upper_cols=lp.upper, width=self.width,
+                              upper_cols=lp.upper, width=width,
                               n_iter=self.n_iter,
                               needs_degree=lp.needs_degree,
                               check_mode=mode,
@@ -432,7 +463,7 @@ class VLFTJ:
                         kw.update(rep_tag=self.gdb.dev("rep_tag"),
                                   bitset_words=self.gdb.dev("bitset_words"))
                     self.stats["chunks"] += 1
-                    self.stats["candidates"] += crows * self.width
+                    self.stats["candidates"] += crows * width
                     # kernel-wall breakdown: bracket the dispatch (and
                     # the host conversion that blocks on it) with two
                     # clock reads — no extra device work either way
@@ -550,17 +581,20 @@ class VLFTJ:
 
         ``repro.results.ResultCursor`` re-enters this level once per
         page with an identical geometry, so non-``bsearch2`` modes are
-        AOT-compiled once per ``(shape, count_only)`` and the compiled
-        executable is dispatched directly — no per-page jit cache probe
-        (static-arg hashing + aval matching).
+        AOT-compiled once per ``(shape, width, count_only)`` and the
+        compiled executable is dispatched directly — no per-page jit cache
+        probe (static-arg hashing + aval matching).  The candidate tile is
+        as wide as the widest width class among the chunk's valid rows.
         """
         lp = self.plan[-1]
         bitmaps = tuple(self.gdb.dev(f"bitmap:{u}") for u in lp.unary)
         mode = self.check_mode if self.check_mode in ("tile", "bsearch2") \
             else "bsearch"
+        width = int(self.row_widths(frontier[row_valid]).max(
+            initial=min(MIN_WIDTH, self.width)))
         kw = dict(probe_cols=lp.edge_sources, n_unary=len(bitmaps),
                   lower_cols=lp.lower, upper_cols=lp.upper,
-                  width=self.width, n_iter=self.n_iter,
+                  width=width, n_iter=self.n_iter,
                   needs_degree=lp.needs_degree, count_only=count_only,
                   check_mode=mode,
                   check_width=self.tile_width if mode == "tile" else 0,
@@ -584,7 +618,7 @@ class VLFTJ:
             # below would drop it; this mode keeps the jitted dispatch
             out = _expand_level(*args, **kw)
         else:
-            key = (frontier.shape, count_only)
+            key = (frontier.shape, width, count_only)
             fn = self._ll_compiled.get(key)
             if fn is None:
                 self.stats["ll_compiles"] += 1
@@ -592,7 +626,7 @@ class VLFTJ:
                 fn = _expand_level.lower(*args, **kw).compile()
                 if prof is not None:
                     prof.record_compile(
-                        f"final_level{frontier.shape}"
+                        f"final_level{frontier.shape}/width={width}"
                         f"/count={count_only}",
                         time.perf_counter() - t_c)
                     t_k = time.perf_counter()   # compile wall kept apart

@@ -16,11 +16,19 @@ device arrays or jitted state stays in threads — the join workloads are
 in the second camp, and that is the right call anyway: the expensive
 part of a join part runs inside XLA, which releases the GIL, so threads
 give real concurrency while sharing one jit cache.
+
+A spawned process worker never touches an accelerator: the chip belongs
+to the process that opened it, and a second process that asks for it
+fails or hangs.  Process workers are therefore pinned to the CPU backend
+(:func:`_host_only_worker`); payloads that need the device never reach
+them, because device state votes 'thread'.
 """
 from __future__ import annotations
 
 import io
+import os
 import pickle
+import sys
 import time
 from concurrent.futures import ProcessPoolExecutor, ThreadPoolExecutor
 from typing import Callable, Sequence
@@ -57,6 +65,15 @@ def pick_backend(fn: Callable, sample_arg=None) -> str:
         return "process"
     except Exception:
         return "thread"
+
+
+def _host_only_worker() -> None:
+    """Process-worker initializer: pin JAX in the child to the CPU before
+    any task can initialize a backend (the parent holds the chip)."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax = sys.modules.get("jax")
+    if jax is not None:      # imported by the spawned main module
+        jax.config.update("jax_platforms", "cpu")
 
 
 def _drain(fn: Callable, owned: list[int], parts: Sequence) -> list[tuple]:
@@ -128,6 +145,7 @@ class WorkerPool:
         if backend == "process":
             import multiprocessing as mp
             kw["mp_context"] = mp.get_context("spawn")
+            kw["initializer"] = _host_only_worker
         with pool_cls(max_workers=len(workers), **kw) as pool:
             futs = {pool.submit(_drain, fn, owned, parts): w
                     for w, owned in workers}

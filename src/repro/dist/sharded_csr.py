@@ -33,7 +33,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec
+from jax.sharding import NamedSharding, PartitionSpec
 
 from ..core.plan import GraphStats, JoinPlan, compile_levels
 from ..core.query import Query
@@ -455,8 +455,11 @@ def spmd_sharded_join_step(mesh, level_kw: dict, sgdb: ShardedGraphDB,
         local_step, mesh=mesh,
         in_specs=(spec, spec, spec, spec), out_specs=PartitionSpec(),
         check_vma=False))
-    indptr_j = jnp.asarray(blocks["indptr"])
-    indices_j = jnp.asarray(blocks["indices"])
+    # each block and frontier slice is placed straight onto its own
+    # device, not staged through the first one
+    sharded = NamedSharding(mesh, spec)
+    indptr_j = jax.device_put(blocks["indptr"], sharded)
+    indices_j = jax.device_put(blocks["indices"], sharded)
 
     def step(frontier, mult):
         frontier = np.asarray(frontier, dtype=np.int32)
@@ -465,8 +468,9 @@ def spmd_sharded_join_step(mesh, level_kw: dict, sgdb: ShardedGraphDB,
         if pad:
             frontier = np.pad(frontier, ((0, pad), (0, 0)))
             mult = np.pad(mult, (0, pad))
-        return int(jitted(indptr_j, indices_j, jnp.asarray(frontier),
-                          jnp.asarray(mult)))
+        return int(jitted(indptr_j, indices_j,
+                          jax.device_put(frontier, sharded),
+                          jax.device_put(mult, sharded)))
 
     step.n_shards = n_dev
     return step

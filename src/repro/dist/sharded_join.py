@@ -25,7 +25,7 @@ from typing import Iterator
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.device_graph import GraphDB
 from ..core.plan import JoinPlan, partition_first_level
@@ -75,6 +75,7 @@ def spmd_join_step(mesh, level_kw: dict, axis_names=None,
         in_specs=(P(), P(), P(axes), P(axes)),
         out_specs=P(), check_vma=False))
 
+    rows_on = NamedSharding(mesh, P(axes))
     callback = getattr(plan, "level_callback", None)
 
     def step(indptr, indices, frontier, mult):
@@ -95,8 +96,9 @@ def spmd_join_step(mesh, level_kw: dict, axis_names=None,
             ml = np.zeros(rows + pad, dtype=np.int64)
             ml[:rows] = np.asarray(mult)
             frontier, mult = fr, ml
-        return jitted(indptr, indices, jnp.asarray(frontier),
-                      jnp.asarray(mult))
+        # rows go straight to the device that owns them
+        return jitted(indptr, indices, jax.device_put(frontier, rows_on),
+                      jax.device_put(mult, rows_on))
 
     step.n_shards = n_shards
     return step

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .backend import pallas_call
+
 DEF_BQ = 128
 DEF_BK = 128
 NEG_INF = -1e30
@@ -81,7 +83,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                            causal: bool = True, scale: float | None = None,
                            bq: int = DEF_BQ, bk: int = DEF_BK,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool | None = None) -> jax.Array:
     """q: (B, Hq, Tq, D); k, v: (B, Hkv, Tk, D); GQA via Hq % Hkv == 0.
 
     Queries are the last Tq positions of the Tk stream (prefill: Tq == Tk).
@@ -104,7 +106,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     def kv_index(h, i, j):
         return ((h // hq) * hkv + (h % hq) // group, j, 0)
 
-    out = pl.pallas_call(
+    out = pallas_call(
         functools.partial(_flash_kernel, scale=scale, causal=causal,
                           bq=bq_, bk=bk_, n_kb=n_kb, q_offset=tk - tq),
         grid=grid,

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .backend import pallas_call
+
 DEF_ROWS = 8     # frontier rows per block (sublane dim)
 DEF_TILE = 128   # values per tile (lane dim)
 
@@ -82,7 +84,7 @@ def _intersect_kernel(a_ref, alen_ref, b_ref, blen_ref, out_ref, *,
 def intersect_count_pallas(a: jax.Array, a_len: jax.Array, b: jax.Array,
                            b_len: jax.Array, rows_per_blk: int = DEF_ROWS,
                            tile: int = DEF_TILE,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool | None = None) -> jax.Array:
     """Per-row |A ∩ B| of padded sorted int32 lists.
 
     a: (R, LA), b: (R, LB) sorted, unique within the valid prefix;
@@ -95,7 +97,7 @@ def intersect_count_pallas(a: jax.Array, a_len: jax.Array, b: jax.Array,
     n_a_tiles = la // tile
     n_b_tiles = lb // tile
     grid = (r // rows_per_blk, n_a_tiles)
-    out = pl.pallas_call(
+    out = pallas_call(
         functools.partial(_intersect_kernel, tile=tile,
                           n_b_tiles=n_b_tiles),
         grid=grid,

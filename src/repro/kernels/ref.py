@@ -1,8 +1,8 @@
 """Pure-jnp oracles for every Pallas kernel (the ``ref.py`` contract).
 
 These are the semantics the TPU kernels must reproduce; they are also the
-default execution path on CPU (the Pallas kernels run under
-``interpret=True`` only in tests on this container).
+default execution path (``kernels/ops.py``).  On the CPU backend the Pallas
+kernels run in the interpreter (``kernels/backend.py``).
 """
 from __future__ import annotations
 

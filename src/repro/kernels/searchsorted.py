@@ -6,11 +6,11 @@ log-time probe LFTJ and Minesweeper both build on (§2.2/§4.5), with the
 B-tree ``seek_lub``/``seek_glb`` replaced by binary search over the
 sorted-array trie.
 
-VMEM layout: the sorted ``values`` array is the kernel's resident block
-(cap ~1M int32 = 4 MB VMEM; larger relations are sharded before the call —
-the engine shards the frontier, not the index).  The midpoint gather uses
-an in-VMEM dynamic gather (``jnp.take``), which lowers to the TPU
-dynamic-gather path on v4+ for 32-bit element types.
+VMEM layout: the sorted ``values`` array is the kernel's resident block,
+and the midpoint gather (``jnp.take``) reads anywhere in it.  The TPU
+compiler lowers gathers only within one 128-lane vreg row, so this kernel
+does not compile for the TPU: it runs in the interpreter on the CPU, and
+``kernels/ops.py`` refuses it on a TPU (``ops.TPU_REFUSED``).
 """
 from __future__ import annotations
 
@@ -19,6 +19,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .backend import pallas_call
 
 DEF_ROWS = 8
 DEF_LANES = 128
@@ -55,7 +57,7 @@ def _searchsorted_kernel(values_ref, lo_ref, hi_ref, q_ref,
 def searchsorted_segments_pallas(values: jax.Array, lo: jax.Array,
                                  hi: jax.Array, queries: jax.Array,
                                  n_iter: int, rows_per_blk: int = DEF_ROWS,
-                                 interpret: bool = True):
+                                 interpret: bool | None = None):
     """Pallas twin of :func:`repro.kernels.ref.searchsorted_segments_ref`.
 
     queries: (R, W); lo/hi broadcastable to (R, W); values: (M,).
@@ -68,7 +70,7 @@ def searchsorted_segments_pallas(values: jax.Array, lo: jax.Array,
     assert r % rows_per_blk == 0 and w % DEF_LANES == 0, (r, w)
     m = values.shape[0]
     grid = (r // rows_per_blk,)
-    pos, found = pl.pallas_call(
+    pos, found = pallas_call(
         functools.partial(_searchsorted_kernel, n_iter=n_iter),
         grid=grid,
         in_specs=[

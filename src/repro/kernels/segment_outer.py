@@ -26,6 +26,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .backend import pallas_call
+
 DEF_TE = 128   # edges per tile
 DEF_BN = 8     # nodes per block
 
@@ -73,7 +75,7 @@ def segment_outer_pallas(msg: jax.Array, basis: jax.Array,
                          dst: jax.Array, block_tile0: jax.Array,
                          n_nodes: int, n_tiles: int, bn: int = DEF_BN,
                          te: int = DEF_TE,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool | None = None) -> jax.Array:
     """msg (E, C), basis (E, M), dst (E,) sorted ascending (pad with
     n_nodes), block_tile0 (n_blocks,) = first edge-tile index overlapping
     each node block, n_tiles = static max tiles per block — both from
@@ -94,7 +96,7 @@ def segment_outer_pallas(msg: jax.Array, basis: jax.Array,
     def dst_index(b, t, starts):
         return (0, jnp.minimum(starts[b] + t, total_tiles - 1))
 
-    out = pl.pallas_call(
+    out = pallas_call(
         functools.partial(_kernel, bn=bn, te=te, n_tiles=n_tiles,
                           total_tiles=total_tiles),
         grid_spec=pltpu.PrefetchScalarGridSpec(

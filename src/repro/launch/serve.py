@@ -15,6 +15,7 @@ import numpy as np
 
 import repro  # noqa: F401
 from repro.graphs import load_edgelist, powerlaw_cluster
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import QueryRequest, QueryServer
 
 MIX = ["3-clique", "4-cycle", "3-path", "4-path", "1-tree", "2-comb",
@@ -30,6 +31,7 @@ def main():
     ap.add_argument("--selectivity", type=float, default=10.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.edgelist:
         g = load_edgelist(args.edgelist)

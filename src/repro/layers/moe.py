@@ -154,8 +154,6 @@ def moe_ffn(x, params_layer, cfg: MoEConfig, mesh, *, act: str = "silu",
             aux = jax.lax.pmean(aux, ax)
         return out.reshape(x_loc.shape).astype(dtype), aux
 
-    # jax.shard_map exists on every supported jax: repro/__init__ bridges
-    # the pre-0.6 experimental spelling (check_rep -> check_vma)
     y, aux = jax.shard_map(
         f, mesh=mesh,
         in_specs=(P(dataxes, None, None), P(), wspec, wspec, wdspec),

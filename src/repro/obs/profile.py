@@ -146,14 +146,11 @@ class DeviceProfile:
         mem["samples"] += 1
         mem["peak_live_bytes"] = max(mem["peak_live_bytes"], nbytes)
         mem["peak_live_buffers"] = max(mem["peak_live_buffers"], len(live))
-        try:
-            dev = jax.devices()[0]
-            stats = dev.memory_stats() if hasattr(dev, "memory_stats") \
-                else None
-        except Exception:
-            stats = None
-        if stats:
-            peak = stats.get("peak_bytes_in_use")
+        # the highest allocator peak over every local device: a sharded
+        # run places arrays on all of them, not on the first alone
+        for dev in jax.local_devices():
+            stats = dev.memory_stats()
+            peak = (stats or {}).get("peak_bytes_in_use")
             if peak is not None:
                 prev = mem["device_peak_bytes"] or 0
                 mem["device_peak_bytes"] = max(prev, int(peak))

@@ -588,7 +588,10 @@ class QuantumScheduler:
             if frontier.shape[0] == 0:
                 self._finish(job, 0)
                 return True
-            frontier = frontier[np.lexsort(frontier.T[::-1])]
+            # width class first, so that a window's final-level tile is
+            # as narrow as its rows allow (VLFTJ._final_level_call)
+            frontier = frontier[np.lexsort(
+                (*frontier.T[::-1], ex.row_widths(frontier)))]
             state = PlanSnapshot(
                 job.req.query_name, ex.gao,
                 frontier.astype(np.int32),

@@ -2,6 +2,7 @@
 worker pool, and the QueryServer -> dist routing path (all single-device
 host-side)."""
 import math
+import os
 
 import jax
 import numpy as np
@@ -133,6 +134,15 @@ def test_worker_pool_process_backend_roundtrip():
     assert backend == "process"
     assert res == {0: 120, 1: 720, 2: 5040, 3: 40320}
     assert set(ptime) == {0, 1, 2, 3} and wall > 0
+
+
+def test_process_workers_are_pinned_to_the_cpu(monkeypatch):
+    # the parent may hold the chip: a spawned worker must never ask for it
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    res, _, _, backend = WorkerPool({0: [0], 1: [1]}, backend="process").run(
+        os.getenv, ["JAX_PLATFORMS", "JAX_PLATFORMS"])
+    assert backend == "process"
+    assert res == {0: "cpu", 1: "cpu"}
 
 
 def test_pool_respects_dead_worker_schedule(gdb):

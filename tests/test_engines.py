@@ -152,3 +152,31 @@ def test_property_hybrid_generalized_lollipops(seed, path_len, clique_k):
     assert hj.count() == ref
     # the decomposition should actually engage for these shapes
     assert hj.decomp.applicable, (path_len, clique_k)
+
+
+def test_width_classes_ladder():
+    from repro.core.plan import MIN_WIDTH, width_classes
+    assert width_classes(2048) == tuple(MIN_WIDTH << i for i in range(7))
+    assert width_classes(MIN_WIDTH) == (MIN_WIDTH,)
+    assert width_classes(16) == (16,)
+
+
+@pytest.mark.parametrize("qname", ["3-clique", "4-cycle", "3-path"])
+def test_rows_dispatch_at_their_width_class(qname):
+    """On a power-law graph most rows have a short probe segment: they run
+    in narrow tiles, not at the hub's width, with the count unchanged."""
+    from repro.core import VLFTJ
+    gdb = make_gdb(400, 3, seed=5)
+    q = get_query(qname)
+    ex = VLFTJ(q, gdb)
+    assert ex.width >= 128
+    assert ex.count() == count(q, gdb, engine="lftj_ref")
+    # at one width for all, every dispatched row would pay ex.width lanes
+    assert ex.stats["candidates"] < ex.stats["rows_expanded"] * ex.width // 2
+    # a mixed chunk of the final level dispatches at its widest row
+    front = np.asarray(VLFTJ(q, gdb).advance(max_levels=len(ex.plan) - 1),
+                       dtype=np.int32)
+    widths = ex.row_widths(front)
+    assert set(np.unique(widths)) <= set(range(32, ex.width + 1))
+    counts = ex.last_level_counts(front)
+    assert counts.sum() == ex.count()

@@ -108,11 +108,12 @@ def test_sharded_train_step_and_elastic_restore():
         from repro.train.loop import make_train_step
         from repro.train.optimizer import OptimizerConfig, init_opt_state
         from repro.train.checkpoint import CheckpointManager
+        from repro.launch.mesh import make_mesh
         cfg = TransformerConfig(name='t', n_layers=2, d_model=64,
                                 n_heads=4, n_kv_heads=2, d_ff=128,
                                 vocab_size=256, dtype=jnp.float32,
                                 remat=False)
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         p = init_params(jax.random.PRNGKey(0), cfg)
         specs = param_specs(cfg)
         shard = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
@@ -130,7 +131,7 @@ def test_sharded_train_step_and_elastic_restore():
         with tempfile.TemporaryDirectory() as d:
             cm = CheckpointManager(d)
             cm.save(1, {'params': p2}, blocking=True)
-            mesh2 = jax.make_mesh((4, 2), ('data', 'model'))
+            mesh2 = make_mesh((4, 2), ('data', 'model'))
             shard2 = jax.tree.map(lambda s: NamedSharding(mesh2, s),
                                   specs,
                                   is_leaf=lambda x: isinstance(x, P))
@@ -153,13 +154,14 @@ def test_moe_shard_map_matches_local():
         import repro
         from repro.layers.moe import MoEConfig, init_moe_params, moe_ffn
         from repro.models.transformer import _moe_ffn_local
+        from repro.launch.mesh import make_mesh
         cfg = MoEConfig(n_experts=8, top_k=2, d_ff_expert=32,
                         capacity_factor=8.0)
         params = init_moe_params(jax.random.PRNGKey(0), 64, cfg, 1)
         lp = jax.tree.map(lambda a: a[0], params)
         x = jnp.asarray(np.random.default_rng(0)
                         .standard_normal((8, 16, 64)), jnp.float32)
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         y_dist, aux_d = moe_ffn(x, lp, cfg, mesh, dtype=jnp.float32)
         # local oracle
         mcfg = dataclasses.replace(cfg)
