@@ -63,21 +63,23 @@ def make_engine(plan: JoinPlan, gdb: GraphDB, **kw):
     dispatch point shared by ``execute``/``execute_stats``/
     ``_engine_rows``).  Every instance carries a ``stats`` dict —
     harvest it through :func:`repro.obs.normalize_engine_stats`."""
-    engine = plan.engine
-    query = plan.query
-    if engine == "vlftj":
-        return VLFTJ(query, gdb, plan=plan, **kw)
-    if engine == "yannakakis":
-        return CountingYannakakis(query, gdb, plan=plan)
-    if engine == "hybrid":
-        return HybridJoin(query, gdb, plan=plan, **kw)
-    if engine == "lftj_ref":
-        return LFTJ(query, gdb.to_database(), plan=plan)
-    if engine == "minesweeper_ref":
-        return Minesweeper(query, gdb.to_database(), plan=plan, **kw)
-    if engine == "binary":
-        return BinaryJoin(query, gdb.to_database(), plan=plan, **kw)
-    raise ValueError(f"unknown engine {engine!r}; options: {ENGINES}")
+    from ..obs.profile import span
+    with span("engine.build", engine=plan.engine):
+        engine = plan.engine
+        query = plan.query
+        if engine == "vlftj":
+            return VLFTJ(query, gdb, plan=plan, **kw)
+        if engine == "yannakakis":
+            return CountingYannakakis(query, gdb, plan=plan)
+        if engine == "hybrid":
+            return HybridJoin(query, gdb, plan=plan, **kw)
+        if engine == "lftj_ref":
+            return LFTJ(query, gdb.to_database(), plan=plan)
+        if engine == "minesweeper_ref":
+            return Minesweeper(query, gdb.to_database(), plan=plan, **kw)
+        if engine == "binary":
+            return BinaryJoin(query, gdb.to_database(), plan=plan, **kw)
+        raise ValueError(f"unknown engine {engine!r}; options: {ENGINES}")
 
 
 def execute(plan: JoinPlan, gdb: GraphDB, **kw) -> int:

@@ -91,32 +91,34 @@ class HybridJoin:
             tree_rows + engine.stats.get("rows_expanded", 0))
 
     def count(self) -> int:
-        d = self.join_plan.decomposition
-        if d is None:
-            if self._core_plan is not None:
-                engine = VLFTJ(self.query, self.gdb, plan=self._core_plan,
-                               **self.vlftj_kw)
-            else:
-                engine = VLFTJ(self.query, self.gdb, **self.vlftj_kw)
-            out = engine.count()
+        from ..obs.profile import span
+        with span("hybrid.count"):
+            d = self.join_plan.decomposition
+            if d is None:
+                if self._core_plan is not None:
+                    engine = VLFTJ(self.query, self.gdb, plan=self._core_plan,
+                                   **self.vlftj_kw)
+                else:
+                    engine = VLFTJ(self.query, self.gdb, **self.vlftj_kw)
+                out = engine.count()
+                self._absorb_core_stats(engine)
+                return out
+            # 1) tree part -> multiplicity vector at the attachment variable
+            cy = CountingYannakakis(d.tree_query, self.gdb, root=d.attachment)
+            msg = np.asarray(cy.message_to_root(d.attachment))
+            if cy._cross_factor != 1:  # disconnected tree pieces: cross factor
+                msg = msg * cy._cross_factor
+            self.stats["spmvs"] = cy.stats.get("spmvs", 0)
+            self.stats["rows_expanded"] = cy.stats.get("rows_expanded", 0)
+            seeds = np.flatnonzero(msg > 0).astype(np.int32)
+            if seeds.size == 0:
+                return 0
+            # 2) core part: GAO = attachment first, then cyclic heuristic
+            engine = VLFTJ(d.core_query, self.gdb, plan=self._core_plan,
+                           **self.vlftj_kw)
+            out = engine.seeded_count(seeds, msg[seeds])
             self._absorb_core_stats(engine)
             return out
-        # 1) tree part -> multiplicity vector at the attachment variable
-        cy = CountingYannakakis(d.tree_query, self.gdb, root=d.attachment)
-        msg = np.asarray(cy.message_to_root(d.attachment))
-        if cy._cross_factor != 1:  # disconnected tree pieces: cross factor
-            msg = msg * cy._cross_factor
-        self.stats["spmvs"] = cy.stats.get("spmvs", 0)
-        self.stats["rows_expanded"] = cy.stats.get("rows_expanded", 0)
-        seeds = np.flatnonzero(msg > 0).astype(np.int32)
-        if seeds.size == 0:
-            return 0
-        # 2) core part: GAO = attachment first, then cyclic heuristic
-        engine = VLFTJ(d.core_query, self.gdb, plan=self._core_plan,
-                       **self.vlftj_kw)
-        out = engine.seeded_count(seeds, msg[seeds])
-        self._absorb_core_stats(engine)
-        return out
 
     def enumerate(self, limit: int | None = None) -> np.ndarray:
         """Full-binding enumeration: int64 tuples, columns in

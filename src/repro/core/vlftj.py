@@ -364,7 +364,7 @@ class VLFTJ:
         # None (the default) keeps every hook below a dead branch, so a
         # disabled profile adds zero work beyond this contextvar read
         # (lazy import: repro.obs pulls in repro.core at package level)
-        from ..obs.profile import current_profile
+        from ..obs.profile import current_profile, span
         prof = current_profile()
         n_levels = len(self.plan) if max_levels is None else max_levels
         lv_rows = self.stats["level_rows"]
@@ -372,7 +372,8 @@ class VLFTJ:
         lv_paths = self.stats["level_paths"]
         if frontier is None:
             t0 = time.perf_counter()
-            frontier = self._domain_values(self.plan[0])[:, None]
+            with span("vlftj.level", level=0, rows=0):
+                frontier = self._domain_values(self.plan[0])[:, None]
             lv_rows[0] = int(frontier.shape[0])
             lv_wall[0] = round(time.perf_counter() - t0, 6)
         frontier = np.asarray(frontier, dtype=np.int32)
@@ -397,123 +398,138 @@ class VLFTJ:
             bitmaps = tuple(gdb.dev(f"bitmap:{u}") for u in lp.unary)
             last = level == n_levels - 1
             last_count = last and count_only
-            self.stats["rows_expanded"] += int(frontier.shape[0])
-            if not lp.edge_sources:
-                frontier, mult, add = self._expand_dense(
-                    frontier, mult, lp, last_count)
-                total += add
+            with span("vlftj.level", level=level,
+                      rows=int(frontier.shape[0])):
+                self.stats["rows_expanded"] += int(frontier.shape[0])
+                if not lp.edge_sources:
+                    frontier, mult, add = self._expand_dense(
+                        frontier, mult, lp, last_count)
+                    total += add
+                    if last_count:
+                        lv_rows[level] = int(total)
+                        lv_wall[level] = (lv_wall.get(level, 0.0) + round(
+                            time.perf_counter() - t_lv, 6))
+                        return total
+                    lv_rows[level] = int(frontier.shape[0])
+                    lv_wall[level] = (lv_wall.get(level, 0.0)
+                                      + round(time.perf_counter() - t_lv, 6))
+                    if prof is not None:
+                        prof.sample_memory()
+                    frontier, mult = boundary(level, frontier, mult)
+                    continue
+                C = frontier.shape[0]
+                if C == 0:
+                    lv_rows[level] = 0
+                    break
+                with span("vlftj.split", level=level):
+                    groups = self._bucket(frontier, mult, lp,
+                                          layout=self.level_layouts[level])
+                    runs = [(f, m, mode, width, chunk)
+                            for gf, gm, mode in groups
+                            for f, m, width, chunk in self._width_split(
+                                gf, gm, level)]
+                paths = lv_paths.setdefault(level, {})
+                for gfrontier, _, mode in groups:
+                    paths[mode] = paths.get(mode, 0) + int(gfrontier.shape[0])
+                new_rows, new_vals, new_mult = [], [], []
+                for gfrontier, gmult, mode, width, chunk_rows in runs:
+                    for s in range(0, gfrontier.shape[0], chunk_rows):
+                        e = min(gfrontier.shape[0], s + chunk_rows)
+                        # one chunk: its arguments, the dispatch, and the
+                        # host conversion that blocks on the result, so the
+                        # device work it causes lies inside this span
+                        with span("vlftj.chunk", profiler_only=True,
+                                  level=level, width=width, rows=e - s,
+                                  mode=mode):
+                            out = self._chunk(
+                                gfrontier[s:e], gmult[s:e], chunk_rows,
+                                bitmaps, lp, mode, width, last_count,
+                                indptr, indices)
+                        if prof is not None:
+                            prof.record_jit_call()
+                        if last_count:
+                            total += out
+                            continue
+                        fchunk, mchunk, cand, keep = out
+                        with span("vlftj.compact", profiler_only=True,
+                                  level=level):
+                            rows, cols = np.nonzero(keep)
+                            new_rows.append(fchunk[rows])
+                            new_vals.append(cand[rows, cols])
+                            new_mult.append(mchunk[rows])
                 if last_count:
                     lv_rows[level] = int(total)
                     lv_wall[level] = (lv_wall.get(level, 0.0)
                                       + round(time.perf_counter() - t_lv, 6))
+                    if prof is not None:
+                        prof.sample_memory()
                     return total
+                with span("vlftj.compact", profiler_only=True, level=level):
+                    frontier = np.concatenate(
+                        [np.concatenate(new_rows, 0) if new_rows else
+                         np.zeros((0, frontier.shape[1]), np.int32),
+                         (np.concatenate(new_vals)[:, None].astype(np.int32)
+                          if new_vals else np.zeros((0, 1), np.int32))],
+                        axis=1)
+                    mult = (np.concatenate(new_mult) if new_mult
+                            else np.zeros(0, np.int64))
+                # record before the boundary callback: a budget callback
+                # may raise (preemption) and the observation must survive
                 lv_rows[level] = int(frontier.shape[0])
                 lv_wall[level] = (lv_wall.get(level, 0.0)
                                   + round(time.perf_counter() - t_lv, 6))
                 if prof is not None:
+                    # memory watermark at the level boundary — the
+                    # engine's host-visible synchronization point, where
+                    # the next level's frontier is fully materialized
                     prof.sample_memory()
                 frontier, mult = boundary(level, frontier, mult)
-                continue
-            C = frontier.shape[0]
-            if C == 0:
-                lv_rows[level] = 0
-                break
-            groups = self._bucket(frontier, mult, lp,
-                                  layout=self.level_layouts[level])
-            paths = lv_paths.setdefault(level, {})
-            for gfrontier, _, mode in groups:
-                paths[mode] = paths.get(mode, 0) + int(gfrontier.shape[0])
-            new_rows, new_vals, new_mult = [], [], []
-            runs = [(f, m, mode, width, chunk)
-                    for gf, gm, mode in groups
-                    for f, m, width, chunk in self._width_split(gf, gm, level)]
-            for gfrontier, gmult, mode, width, chunk_rows in runs:
-                for s in range(0, gfrontier.shape[0], chunk_rows):
-                    e = min(gfrontier.shape[0], s + chunk_rows)
-                    # pad a partial chunk only to the next power of two:
-                    # kernel cost tracks live rows (a 100-row tail no
-                    # longer dispatches a full chunk_rows kernel) while
-                    # the jit cache stays bounded at log2(chunk_rows)
-                    # shapes per static-arg combo and width class
-                    crows = min(chunk_rows,
-                                max(8, 1 << (e - s - 1).bit_length()))
-                    pad = crows - (e - s)
-                    fchunk = np.pad(gfrontier[s:e], ((0, pad), (0, 0)))
-                    mchunk = np.pad(gmult[s:e], (0, pad))
-                    rv = np.zeros(crows, dtype=bool)
-                    rv[: e - s] = True
-                    args = (indptr, indices, bitmaps, jnp.asarray(fchunk),
-                            jnp.asarray(mchunk), jnp.asarray(rv))
-                    kw = dict(probe_cols=lp.edge_sources,
-                              n_unary=len(bitmaps), lower_cols=lp.lower,
-                              upper_cols=lp.upper, width=width,
-                              n_iter=self.n_iter,
-                              needs_degree=lp.needs_degree,
-                              check_mode=mode,
-                              check_width=(self.tile_width
-                                           if mode == "tile" else 0),
-                              rotate_checks=self.rotate_checks)
-                    if mode == "bsearch2":
-                        kw.update(
-                            n_iter=self.n_iter1, n_iter2=self.n_iter2,
-                            summary=self.gdb.dev(
-                                f"summary:{self.summary_stride}"),
-                            summary_stride=self.summary_stride)
-                    elif mode == "bitset":
-                        kw.update(rep_tag=self.gdb.dev("rep_tag"),
-                                  bitset_words=self.gdb.dev("bitset_words"))
-                    self.stats["chunks"] += 1
-                    self.stats["candidates"] += crows * width
-                    # kernel-wall breakdown: bracket the dispatch (and
-                    # the host conversion that blocks on it) with two
-                    # clock reads — no extra device work either way
-                    t_k = 0.0 if prof is None else time.perf_counter()
-                    if last_count:
-                        total += int(np.asarray(_expand_level(
-                            *args, count_only=True, **kw)).sum())
-                    else:
-                        cand, keep = (np.asarray(x) for x in _expand_level(
-                            *args, count_only=False, **kw))
-                        rows, cols = np.nonzero(keep)
-                        new_rows.append(fchunk[rows])
-                        new_vals.append(cand[rows, cols])
-                        new_mult.append(mchunk[rows])
-                    if prof is not None:
-                        prof.record_jit_call()
-                        prof.record_kernel(
-                            "intersect_bitset" if mode == "bitset"
-                            else "intersect",
-                            time.perf_counter() - t_k)
-            if last_count:
-                lv_rows[level] = int(total)
-                lv_wall[level] = (lv_wall.get(level, 0.0)
-                                  + round(time.perf_counter() - t_lv, 6))
-                if prof is not None:
-                    prof.sample_memory()
-                return total
-            frontier = np.concatenate(
-                [np.concatenate(new_rows, 0) if new_rows else
-                 np.zeros((0, frontier.shape[1]), np.int32),
-                 (np.concatenate(new_vals)[:, None].astype(np.int32)
-                  if new_vals else np.zeros((0, 1), np.int32))], axis=1)
-            mult = (np.concatenate(new_mult) if new_mult
-                    else np.zeros(0, np.int64))
-            # record before the boundary callback: a budget callback may
-            # raise (preemption) and the observation must survive it
-            lv_rows[level] = int(frontier.shape[0])
-            lv_wall[level] = (lv_wall.get(level, 0.0)
-                              + round(time.perf_counter() - t_lv, 6))
-            if prof is not None:
-                # memory watermark at the level boundary — the engine's
-                # host-visible synchronization point, where the frontier
-                # for the next level is fully materialized
-                prof.sample_memory()
-            frontier, mult = boundary(level, frontier, mult)
-            self.stats["frontier_peak"] = max(self.stats["frontier_peak"],
-                                              frontier.shape[0])
+                self.stats["frontier_peak"] = max(
+                    self.stats["frontier_peak"], frontier.shape[0])
         if count_only:
             return int(mult.sum())
         return frontier
+
+    def _chunk(self, frontier, mult, chunk_rows, bitmaps, lp, mode, width,
+               last_count, indptr, indices):
+        """Dispatch one chunk of a level's run and wait for it: the
+        weighted count of its survivors (``last_count``), else
+        ``(padded frontier, padded mult, candidates, keep mask)`` on the
+        host."""
+        real = frontier.shape[0]
+        # pad a partial chunk only to the next power of two: kernel cost
+        # tracks live rows (a 100-row tail no longer dispatches a full
+        # chunk_rows kernel) while the jit cache stays bounded at
+        # log2(chunk_rows) shapes per static-arg combo and width class
+        crows = min(chunk_rows, max(8, 1 << (real - 1).bit_length()))
+        pad = crows - real
+        fchunk = np.pad(frontier, ((0, pad), (0, 0)))
+        mchunk = np.pad(mult, (0, pad))
+        rv = np.zeros(crows, dtype=bool)
+        rv[:real] = True
+        args = (indptr, indices, bitmaps, jnp.asarray(fchunk),
+                jnp.asarray(mchunk), jnp.asarray(rv))
+        kw = dict(probe_cols=lp.edge_sources, n_unary=len(bitmaps),
+                  lower_cols=lp.lower, upper_cols=lp.upper, width=width,
+                  n_iter=self.n_iter, needs_degree=lp.needs_degree,
+                  check_mode=mode,
+                  check_width=self.tile_width if mode == "tile" else 0,
+                  rotate_checks=self.rotate_checks)
+        if mode == "bsearch2":
+            kw.update(n_iter=self.n_iter1, n_iter2=self.n_iter2,
+                      summary=self.gdb.dev(f"summary:{self.summary_stride}"),
+                      summary_stride=self.summary_stride)
+        elif mode == "bitset":
+            kw.update(rep_tag=self.gdb.dev("rep_tag"),
+                      bitset_words=self.gdb.dev("bitset_words"))
+        self.stats["chunks"] += 1
+        self.stats["candidates"] += crows * width
+        if last_count:
+            return int(np.asarray(_expand_level(
+                *args, count_only=True, **kw)).sum())
+        cand, keep = (np.asarray(x) for x in _expand_level(
+            *args, count_only=False, **kw))
+        return fchunk, mchunk, cand, keep
 
     # -- enumeration support -------------------------------------------------
     def last_level_counts(self, frontier: np.ndarray,
@@ -603,44 +619,40 @@ class VLFTJ:
             kw.update(n_iter=self.n_iter1, n_iter2=self.n_iter2,
                       summary=self.gdb.dev(f"summary:{self.summary_stride}"),
                       summary_stride=self.summary_stride)
-        args = (self.gdb.dev("indptr"), self.gdb.dev("indices"), bitmaps,
-                jnp.asarray(frontier),
-                jnp.ones(frontier.shape[0], dtype=jnp.int64),
-                jnp.asarray(row_valid))
-        self.stats["ll_calls"] += 1
-        from ..obs.profile import current_profile
-        prof = current_profile()
-        if prof is not None:
-            prof.record_jit_call()
-            t_k = time.perf_counter()
-        if mode == "bsearch2":
-            # summary is a traced kwarg, not a static — the AOT signature
-            # below would drop it; this mode keeps the jitted dispatch
-            out = _expand_level(*args, **kw)
-        else:
-            key = (frontier.shape, width, count_only)
-            fn = self._ll_compiled.get(key)
-            if fn is None:
-                self.stats["ll_compiles"] += 1
-                t_c = time.perf_counter()
-                fn = _expand_level.lower(*args, **kw).compile()
-                if prof is not None:
-                    prof.record_compile(
-                        f"final_level{frontier.shape}/width={width}"
-                        f"/count={count_only}",
-                        time.perf_counter() - t_c)
-                    t_k = time.perf_counter()   # compile wall kept apart
-                self._ll_compiled[key] = fn
-            out = fn(*args)
-        if count_only:
-            out = np.asarray(out)
+        from ..obs.profile import current_profile, span
+        with span("vlftj.final", width=width,
+                  rows=int(np.count_nonzero(row_valid))):
+            args = (self.gdb.dev("indptr"), self.gdb.dev("indices"),
+                    bitmaps, jnp.asarray(frontier),
+                    jnp.ones(frontier.shape[0], dtype=jnp.int64),
+                    jnp.asarray(row_valid))
+            self.stats["ll_calls"] += 1
+            prof = current_profile()
             if prof is not None:
-                prof.record_kernel("intersect", time.perf_counter() - t_k)
-            return out
-        out = tuple(np.asarray(x) for x in out)
-        if prof is not None:
-            prof.record_kernel("intersect", time.perf_counter() - t_k)
-        return out
+                prof.record_jit_call()
+            if mode == "bsearch2":
+                # summary is a traced kwarg, not a static — the AOT
+                # signature below would drop it; this mode keeps the
+                # jitted dispatch
+                out = _expand_level(*args, **kw)
+            else:
+                key = (frontier.shape, width, count_only)
+                fn = self._ll_compiled.get(key)
+                if fn is None:
+                    self.stats["ll_compiles"] += 1
+                    t_c = time.perf_counter()
+                    with span("vlftj.compile", width=width):
+                        fn = _expand_level.lower(*args, **kw).compile()
+                    if prof is not None:
+                        prof.record_compile(
+                            f"final_level{frontier.shape}/width={width}"
+                            f"/count={count_only}",
+                            time.perf_counter() - t_c)
+                    self._ll_compiled[key] = fn
+                out = fn(*args)
+            if count_only:
+                return np.asarray(out)
+            return tuple(np.asarray(x) for x in out)
 
     # -- public API ----------------------------------------------------------
     def count(self) -> int:
@@ -660,7 +672,9 @@ class VLFTJ:
         k = len(self.plan)
         if rows.shape[0] == 0:
             return np.zeros((0, k), dtype=np.int64)
-        rows = rows[np.lexsort(rows.T[::-1])]
+        from ..obs.profile import span
+        with span("vlftj.sort", rows=int(rows.shape[0])):
+            rows = rows[np.lexsort(rows.T[::-1])]
         return rows if limit is None else rows[:limit]
 
     @property

@@ -175,56 +175,63 @@ class CountingYannakakis:
         return roots
 
     def count(self) -> int:
-        c_root = self.message_to_root()
-        return int(c_root.sum()) * self._cross_factor
+        # lazy: repro.obs pulls in repro.core at package level
+        from ..obs.profile import span
+        # int() waits for the root's sum, so every SpMV this count
+        # dispatched runs inside the span
+        with span("yannakakis.count", query=self.query.name):
+            c_root = self.message_to_root()
+            return int(c_root.sum()) * self._cross_factor
 
     def semijoin_reduce(self) -> dict[str, np.ndarray]:
         """Active-value masks per variable after full semijoin reduction
         (upward + downward passes) — the enumeration prefilter."""
-        indptr = self.gdb.dev("indptr")
-        indices = self.gdb.dev("indices")
-        src_ids = self.gdb.dev("src_ids")
-        n = self.gdb.n_nodes
-        up_msg: dict[tuple[str, str], jnp.ndarray] = {}
+        from ..obs.profile import span
+        with span("yannakakis.semijoin", query=self.query.name):
+            indptr = self.gdb.dev("indptr")
+            indices = self.gdb.dev("indices")
+            src_ids = self.gdb.dev("src_ids")
+            n = self.gdb.n_nodes
+            up_msg: dict[tuple[str, str], jnp.ndarray] = {}
 
-        def up(var: str, parent: str | None) -> jnp.ndarray:
-            c = self._unary_mask(var) > 0
-            for ch in self.adj[var]:
-                if ch == parent:
-                    continue
-                m = up(ch, var)
-                self.stats["spmvs"] += 1
-                self.stats["rows_expanded"] += n
-                c = c & (_spmv(indptr, indices, src_ids,
-                               m.astype(jnp.int64), num_segments=n) > 0)
-            if parent is not None:
-                up_msg[(var, parent)] = c
-            return c
+            def up(var: str, parent: str | None) -> jnp.ndarray:
+                c = self._unary_mask(var) > 0
+                for ch in self.adj[var]:
+                    if ch == parent:
+                        continue
+                    m = up(ch, var)
+                    self.stats["spmvs"] += 1
+                    self.stats["rows_expanded"] += n
+                    c = c & (_spmv(indptr, indices, src_ids,
+                                   m.astype(jnp.int64), num_segments=n) > 0)
+                if parent is not None:
+                    up_msg[(var, parent)] = c
+                return c
 
-        active: dict[str, jnp.ndarray] = {}
+            active: dict[str, jnp.ndarray] = {}
 
-        def down(var: str, parent: str | None, mask_from_parent):
-            c = self._unary_mask(var) > 0
-            if mask_from_parent is not None:
-                c = c & mask_from_parent
-            for ch in self.adj[var]:
-                if ch == parent:
-                    continue
-                c = c & (_spmv(indptr, indices, src_ids,
-                               up_msg[(ch, var)].astype(jnp.int64),
-                               num_segments=n) > 0)
-            active[var] = c
-            for ch in self.adj[var]:
-                if ch == parent:
-                    continue
-                m = _spmv(indptr, indices, src_ids, c.astype(jnp.int64),
-                          num_segments=n) > 0
-                down(ch, var, m)
+            def down(var: str, parent: str | None, mask_from_parent):
+                c = self._unary_mask(var) > 0
+                if mask_from_parent is not None:
+                    c = c & mask_from_parent
+                for ch in self.adj[var]:
+                    if ch == parent:
+                        continue
+                    c = c & (_spmv(indptr, indices, src_ids,
+                                   up_msg[(ch, var)].astype(jnp.int64),
+                                   num_segments=n) > 0)
+                active[var] = c
+                for ch in self.adj[var]:
+                    if ch == parent:
+                        continue
+                    m = _spmv(indptr, indices, src_ids, c.astype(jnp.int64),
+                              num_segments=n) > 0
+                    down(ch, var, m)
 
-        for r in self._component_roots(self.root):
-            up(r, None)
-            down(r, None, None)
-        return {v: np.asarray(m) for v, m in active.items()}
+            for r in self._component_roots(self.root):
+                up(r, None)
+                down(r, None, None)
+            return {v: np.asarray(m) for v, m in active.items()}
 
     def enumerate(self, limit: int | None = None) -> np.ndarray:
         """Backward-expansion enumeration: int64 tuples, columns in GAO

@@ -1,20 +1,21 @@
-"""Device-side profiling: jit compiles, kernel walls, memory watermarks.
+"""Device-side profiling: program spans, jit compiles, memory watermarks.
 
 :class:`~repro.obs.trace.QueryTrace` (PR 8) answers *what* a query did
 per GAO level — est-vs-observed cardinality, kernel-path mix, scheduler
-events.  :class:`DeviceProfile` answers *why a level got slow* one layer
-down:
+events.  This module answers *where the time went* one layer down:
 
+* **spans** — :func:`span` names a stretch of host work (a server
+  phase, a GAO level, one chunk's dispatch, a compaction) as a
+  ``jax.profiler.TraceAnnotation`` called ``repro.<name>``.  Under a
+  profiler trace the span lands on the same clock as the device's
+  operations, so a device-idle gap can be attributed to the innermost
+  span open across it; with no profiler running it costs a microsecond
+  or two and records nothing.  Spans are always on: the catalogue
+  is in ``docs/OBSERVABILITY.md``.
 * **jit** — compile vs cached-call counts and compile wall seconds,
   harvested at the engine's two dispatch sites (the
   ``VLFTJ._final_level_call`` AOT cache and the interior chunked
   ``_expand_level`` dispatches);
-* **kernels** — a per-family host-wall breakdown (``intersect``,
-  ``intersect_bitset``, ``segment_outer``): each dispatch the engine
-  already performs is bracketed by two ``perf_counter`` reads, so the
-  breakdown costs two clock reads per chunk and **zero extra device
-  dispatches** — the same discipline as tracing, guarded by
-  ``tests/test_profile.py``;
 * **memory** — live-buffer watermarks sampled at GAO level boundaries
   (``jax.live_arrays()`` metadata only — ``nbytes`` is shape×dtype
   arithmetic, no device sync), plus the backend allocator's
@@ -26,26 +27,29 @@ down:
   (``sched-3/q2``), so a compile storm is attributable to the job and
   quantum that triggered it.
 
-Off by default: every hook is ``prof = current_profile(); if prof is
-None: <nothing>``.  Activation mirrors tracing — a contextvar, so the
-scheduler, pool, and cursor find the profile without signature
-threading.  :meth:`DeviceProfile.publish` pushes the harvest into a
-:class:`~repro.obs.trace.QueryTrace` (as spans) and a
-:class:`~repro.obs.metrics.MetricsRegistry` (as histograms/counters) so
-one export surface carries all three layers.
+:class:`DeviceProfile` is off by default: every hook is ``prof =
+current_profile(); if prof is None: <nothing>``.  Activation mirrors
+tracing — a contextvar, so the scheduler, pool, and cursor find the
+profile without signature threading.  :meth:`DeviceProfile.publish`
+pushes the harvest into a :class:`~repro.obs.trace.QueryTrace` (as a
+span) and a :class:`~repro.obs.metrics.MetricsRegistry` (as
+histograms/counters) so one export surface carries all three layers.
 """
 from __future__ import annotations
 
 import contextlib
 import contextvars
-import math
 import time
+
+from jax.profiler import TraceAnnotation
+
+from .trace import current_trace
 
 #: schema version stamped into every profile dict export.
 PROFILE_SCHEMA_VERSION = 1
 
-#: kernel families the wall breakdown buckets dispatches into.
-KERNEL_FAMILIES = ("intersect", "intersect_bitset", "segment_outer")
+#: what every program span's profiler name starts with
+SPAN_PREFIX = "repro."
 
 _ACTIVE: contextvars.ContextVar["DeviceProfile | None"] = \
     contextvars.ContextVar("repro_obs_active_profile", default=None)
@@ -54,6 +58,35 @@ _ACTIVE: contextvars.ContextVar["DeviceProfile | None"] = \
 def current_profile() -> "DeviceProfile | None":
     """The profile active in this context, or None (profiling disabled)."""
     return _ACTIVE.get()
+
+
+def span(name: str, *, profiler_only: bool = False, **attrs):
+    """A context manager that names a stretch of program work.
+
+    It always enters ``jax.profiler.TraceAnnotation("repro." + name,
+    **attrs)``, which a running profiler records on the host's timeline
+    with ``attrs`` as the event's stats (ints, floats and strings).
+    When a :class:`~repro.obs.trace.QueryTrace` is active it also
+    records the span there (:meth:`QueryTrace.span`), unless
+    ``profiler_only``: spans opened once per chunk pass it, so that a
+    traced request keeps one record per level and phase.
+
+    Example::
+
+        with span("vlftj.level", level=2, rows=frontier.shape[0]):
+            ...
+    """
+    ann = TraceAnnotation(SPAN_PREFIX + name, **attrs)
+    tr = None if profiler_only else current_trace()
+    if tr is None:
+        return ann
+    return _in_both(ann, tr.span(name, **attrs))
+
+
+@contextlib.contextmanager
+def _in_both(annotation, trace_span):
+    with annotation, trace_span:
+        yield
 
 
 class DeviceProfile:
@@ -68,9 +101,9 @@ class DeviceProfile:
             counts every jitted/AOT kernel dispatch; ``compiles`` counts
             observable (AOT) compilations and ``compile_wall_s`` their
             summed wall seconds.  Interior first-call trace+compile time
-            is not separable host-side; it shows up in that dispatch's
-            kernel wall instead.
-        kernels: family -> ``{"calls", "wall_s"}`` host-wall breakdown.
+            is not separable host-side; a profiler trace shows it as
+            JAX's own events inside that dispatch's ``vlftj.chunk``
+            span.
         memory: live-buffer watermarks — ``peak_live_bytes`` /
             ``peak_live_buffers`` over the samples taken at level
             boundaries, ``samples``, and ``device_peak_bytes`` (backend
@@ -85,7 +118,6 @@ class DeviceProfile:
         self.meta = {"query": query_name, "engine": engine,
                      "schema": PROFILE_SCHEMA_VERSION}
         self.jit = {"compiles": 0, "calls": 0, "compile_wall_s": 0.0}
-        self.kernels: dict[str, dict] = {}
         self.memory = {"samples": 0, "peak_live_bytes": 0,
                        "peak_live_buffers": 0, "device_peak_bytes": None}
         self.compile_events: list[dict] = []
@@ -111,12 +143,6 @@ class DeviceProfile:
         self.compile_events.append(
             {"key": str(key), "wall_s": round(float(wall_s), 6),
              "attribution": self.attribution, "t": self._now()})
-
-    def record_kernel(self, family: str, wall_s: float,
-                      calls: int = 1) -> None:
-        rec = self.kernels.setdefault(family, {"calls": 0, "wall_s": 0.0})
-        rec["calls"] += calls
-        rec["wall_s"] += float(wall_s)
 
     def record_worker(self, worker: int, backend: str,
                       dur_s: float) -> None:
@@ -183,9 +209,6 @@ class DeviceProfile:
                 "jit": {**self.jit,
                         "compile_wall_s": round(self.jit["compile_wall_s"],
                                                 6)},
-                "kernels": {f: {"calls": r["calls"],
-                                "wall_s": round(r["wall_s"], 6)}
-                            for f, r in sorted(self.kernels.items())},
                 "memory": dict(self.memory),
                 "compile_events": list(self.compile_events),
                 "worker_spans": list(self.worker_spans)}
@@ -193,12 +216,10 @@ class DeviceProfile:
     def publish(self, trace=None, registry=None) -> None:
         """Push the harvest into the other observability surfaces.
 
-        ``trace``: one ``profile/jit`` span (compile counts + wall) and
-        one ``profile/kernel/<family>`` span per family, plus the memory
-        watermark on the trace summary.  ``registry``: histograms
-        ``profile_compile_seconds`` and ``profile_kernel_seconds{
-        family=...}``, counter ``profile_jit_calls``, gauge
-        ``profile_peak_live_bytes``.
+        ``trace``: one ``profile/jit`` span (compile counts + wall), plus
+        the memory watermark on the trace summary.  ``registry``:
+        histogram ``profile_compile_seconds``, counter
+        ``profile_jit_calls``, gauge ``profile_peak_live_bytes``.
         """
         if trace is not None:
             trace.spans.append({
@@ -206,11 +227,6 @@ class DeviceProfile:
                 "compiles": self.jit["compiles"],
                 "calls": self.jit["calls"],
                 "dur_s": round(self.jit["compile_wall_s"], 6)})
-            for fam, rec in sorted(self.kernels.items()):
-                trace.spans.append({
-                    "name": f"profile/kernel/{fam}", "t": 0.0,
-                    "calls": rec["calls"],
-                    "dur_s": round(rec["wall_s"], 6)})
             if self.memory["samples"]:
                 trace.summary.setdefault(
                     "peak_live_bytes", self.memory["peak_live_bytes"])
@@ -218,20 +234,11 @@ class DeviceProfile:
             for ev in self.compile_events:
                 registry.histogram("profile_compile_seconds").observe(
                     ev["wall_s"])
-            for fam, rec in self.kernels.items():
-                registry.histogram("profile_kernel_seconds",
-                                   family=fam).observe(rec["wall_s"])
             if self.jit["calls"]:
                 registry.counter("profile_jit_calls").inc(self.jit["calls"])
             if self.memory["samples"]:
                 g = registry.gauge("profile_peak_live_bytes")
                 g.set(max(g.value, self.memory["peak_live_bytes"]))
-
-    # -- derived views -------------------------------------------------------
-    def kernel_wall_s(self, family: str | None = None) -> float:
-        if family is not None:
-            return self.kernels.get(family, {}).get("wall_s", 0.0)
-        return math.fsum(r["wall_s"] for r in self.kernels.values())
 
 
 class NullProfile:
@@ -250,9 +257,6 @@ class NullProfile:
         pass
 
     def record_compile(self, key, wall_s):
-        pass
-
-    def record_kernel(self, family, wall_s, calls=1):
         pass
 
     def record_worker(self, worker, backend, dur_s):

@@ -35,28 +35,12 @@ page interface so the query server paginates every engine uniformly.
 """
 from __future__ import annotations
 
-import time
 from typing import Iterable, Iterator
 
 import numpy as np
 
 from ..core.vlftj import VLFTJ
 from ..kernels.segment_outer import segment_expand
-
-
-def _segment_expand(prefix, counts, vals):
-    """``segment_expand`` with the device-profile kernel-wall hook —
-    two clock reads when a profile is active, nothing otherwise."""
-    # lazy: repro.obs pulls in repro.core at package level
-    from ..obs.profile import current_profile
-    prof = current_profile()
-    if prof is None:
-        return segment_expand(prefix, counts, vals)
-    t0 = time.perf_counter()
-    out = segment_expand(prefix, counts, vals)
-    prof.record_jit_call()
-    prof.record_kernel("segment_outer", time.perf_counter() - t0)
-    return out
 
 
 class ResultCursor:
@@ -213,7 +197,7 @@ class ResultCursor:
                 self.stats["chunks"] += 1
                 for s in range(0, vals.shape[0], self.page_rows):
                     part = vals[s:s + self.page_rows]
-                    yield _segment_expand(
+                    yield segment_expand(
                         frontier[i:i + 1],
                         np.array([part.shape[0]], dtype=np.int64), part)
             return
@@ -260,37 +244,39 @@ class ResultCursor:
                     chunk.astype(np.int32), valid)
                 self.stats["chunks"] += 1
                 if vals.shape[0]:
-                    yield _segment_expand(chunk[:real], ccounts[:real],
-                                          vals)
+                    yield segment_expand(chunk[:real], ccounts[:real], vals)
                 i = j
 
     # -- paging --------------------------------------------------------------
     def take(self, n: int | None = None) -> np.ndarray:
         """The next ``n`` rows (default ``page_rows``); empty when drained."""
         n = self.page_rows if n is None else n
-        while self._buffered < n and not self._drained:
-            try:
-                block = next(self._blocks)
-            except StopIteration:
-                self._drained = True
-                break
-            if block.shape[0]:
-                self._buf.append(block)
-                self._buffered += int(block.shape[0])
-                self.stats["peak_buffer_rows"] = max(
-                    self.stats["peak_buffer_rows"], self._buffered)
-        if self._buf:
-            cat = (self._buf[0] if len(self._buf) == 1
-                   else np.concatenate(self._buf, axis=0))
-            out, rest = cat[:n], cat[n:]
-            self._buf = [rest] if rest.shape[0] else []
-            self._buffered = int(rest.shape[0])
-        else:
-            out = np.zeros((0, self._k), dtype=np.int64)
-        self.stats["pages"] += 1
-        self.stats["rows"] += int(out.shape[0])
-        self.exhausted = self._drained and self._buffered == 0
-        return out
+        # lazy: repro.obs pulls in repro.core at package level
+        from ..obs.profile import span
+        with span("cursor.take", rows=int(n)):
+            while self._buffered < n and not self._drained:
+                try:
+                    block = next(self._blocks)
+                except StopIteration:
+                    self._drained = True
+                    break
+                if block.shape[0]:
+                    self._buf.append(block)
+                    self._buffered += int(block.shape[0])
+                    self.stats["peak_buffer_rows"] = max(
+                        self.stats["peak_buffer_rows"], self._buffered)
+            if self._buf:
+                cat = (self._buf[0] if len(self._buf) == 1
+                       else np.concatenate(self._buf, axis=0))
+                out, rest = cat[:n], cat[n:]
+                self._buf = [rest] if rest.shape[0] else []
+                self._buffered = int(rest.shape[0])
+            else:
+                out = np.zeros((0, self._k), dtype=np.int64)
+            self.stats["pages"] += 1
+            self.stats["rows"] += int(out.shape[0])
+            self.exhausted = self._drained and self._buffered == 0
+            return out
 
     @property
     def rows_emitted(self) -> int:

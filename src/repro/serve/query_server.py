@@ -44,7 +44,7 @@ from ..core import GraphDB, GraphStats, JoinPlan, PlanCache, get_query
 from ..core import engine as engine_mod
 from ..graphs import CSRGraph, node_sample
 from ..obs import DeviceProfile, MetricsRegistry, QueryTrace, \
-    get_registry, normalize_engine_stats
+    get_registry, normalize_engine_stats, span
 from ..results import ResultCursor
 
 
@@ -78,10 +78,10 @@ class QueryRequest:
     #: nothing (``tests/test_obs.py`` guards zero extra device dispatches).
     trace: bool = False
     #: record a :class:`repro.obs.DeviceProfile` for this request — jit
-    #: compile/call counts + compile wall, per-kernel wall breakdown,
-    #: memory watermarks — returned as ``QueryResult.profile`` and
-    #: published into the server's metrics registry.  Off by default with
-    #: the same zero-device-dispatch guarantee (``tests/test_profile.py``).
+    #: compile/call counts + compile wall, memory watermarks — returned
+    #: as ``QueryResult.profile`` and published into the server's metrics
+    #: registry.  Off by default with the same zero-device-dispatch
+    #: guarantee (``tests/test_profile.py``).
     profile: bool = False
 
     @property
@@ -226,7 +226,6 @@ class QueryServer:
         reg = self.metrics_registry
         reg.gauge("server_open_cursors").set(len(self._cursors))
         reg.gauge("server_plan_cache_size").set(len(self.plan_cache))
-        reg.counter("server_metrics_snapshots").inc()
         return reg.snapshot()
 
     # -- request log ---------------------------------------------------------
@@ -315,16 +314,19 @@ class QueryServer:
     def _gdb_for(self, selectivity: float, seed: int) -> GraphDB:
         key = (round(selectivity, 6), seed)
         if key not in self._warm:
-            unary = {f"v{i}": node_sample(self.csr.n_nodes, selectivity,
-                                          seed=seed * 7 + i)
-                     for i in range(1, 5)}
-            self._warm[key] = GraphDB(self.csr, unary)
+            with span("server.graph", selectivity=float(selectivity),
+                      seed=int(seed)):
+                unary = {f"v{i}": node_sample(self.csr.n_nodes,
+                                              selectivity, seed=seed * 7 + i)
+                         for i in range(1, 5)}
+                self._warm[key] = GraphDB(self.csr, unary)
         return self._warm[key]
 
     def _stats_for(self, gdb: GraphDB) -> GraphStats:
         key = id(gdb)
         if key not in self._stats:
-            self._stats[key] = GraphStats.of(gdb)
+            with span("server.stats"):
+                self._stats[key] = GraphStats.of(gdb)
         return self._stats[key]
 
     def _plan_for(self, req: QueryRequest, gdb: GraphDB,
@@ -341,12 +343,14 @@ class QueryServer:
         q = get_query(req.query_name)
         stats = self._stats_for(gdb)
         hits_before = self.plan_cache.hits
-        plan = self.plan_cache.get_or_plan(q, stats, req.engine,
-                                           output=output)
+        with span("server.plan", query=req.query_name):
+            plan = self.plan_cache.get_or_plan(q, stats, req.engine,
+                                               output=output)
         hit = self.plan_cache.hits > hits_before
         self.metrics_registry.counter(
             "server_plan_cache", outcome="hit" if hit else "miss").inc()
-        verify_for_execution(plan, gdb)
+        with span("server.verify"):
+            verify_for_execution(plan, gdb)
         return plan, hit
 
     def plan_cache_info(self) -> dict:
@@ -373,8 +377,8 @@ class QueryServer:
                      label: str, plan: JoinPlan | None, cached: bool,
                      token: str | None, t0: float,
                      trace_id: str | None = None) -> QueryResult:
-        # per-page profile: the enumeration kernels (segment_outer)
-        # dispatch inside take(), so the activation brackets it
+        # per-page profile: the final-level kernels of a page dispatch
+        # inside take(), so the activation brackets it
         prof = (DeviceProfile(req.query_name, label) if req.profile
                 else None)
         with contextlib.ExitStack() as stack:
@@ -433,7 +437,9 @@ class QueryServer:
         t0 = time.time()
         trace_id = self._next_trace_id()
         try:
-            res = self._execute_impl(req, t0, trace_id)
+            with span("server.execute", req=trace_id, query=req.query_name,
+                      tenant=req.tenant):
+                res = self._execute_impl(req, t0, trace_id)
         except Exception as e:
             self._log_request(trace_id, req, t0, error=e)
             raise

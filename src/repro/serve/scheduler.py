@@ -49,7 +49,7 @@ import numpy as np
 
 from ..core import VLFTJ, get_query
 from ..core.plan import pow2ceil
-from ..obs import DeviceProfile, QueryTrace
+from ..obs import DeviceProfile, QueryTrace, span
 from ..results import ResultCursor
 from .query_server import QueryRequest, QueryResult, QueryServer
 
@@ -343,31 +343,35 @@ class QuantumScheduler:
         if req.cursor is not None:
             raise ValueError("cursor continuations resume via "
                              "QueryServer.execute, not the scheduler")
-        quota = self.quota_for(req.tenant)
-        if self._in_flight.get(req.tenant, 0) >= quota.max_in_flight:
-            self.stats["rejected"] += 1
-            raise AdmissionError(
-                req.tenant, f"max_in_flight={quota.max_in_flight} reached")
-        if self._tenant_parked_bytes(req.tenant) >= quota.max_frontier_bytes:
-            self.stats["rejected"] += 1
-            raise AdmissionError(
-                req.tenant,
-                f"parked frontier bytes over "
-                f"max_frontier_bytes={quota.max_frontier_bytes}")
-        sel = req.selectivity or self.server.default_selectivity
-        gdb = self.server._gdb_for(sel, req.seed)
-        output = "rows" if req.limit is not None else "count"
-        plan, _cached = self.server._plan_for(req, gdb, output=output)
-        budget = QuantumBudget(
-            None if self.policy == "fifo" else self.quantum_rows,
-            req.query_name, plan.gao, inner=plan.level_callback)
-        self._seq += 1
-        job = _Job(self._seq, req, plan, gdb, plan.engine, budget,
-                   collect_rows, self.vclock)
-        self._jobs.append(job)
-        self._queue.append(job)
-        self._in_flight[req.tenant] = self._in_flight.get(req.tenant, 0) + 1
-        return job.token
+        # the id this request's job gets if it is admitted
+        with span("scheduler.submit", job=self._seq + 1, tenant=req.tenant):
+            quota = self.quota_for(req.tenant)
+            if self._in_flight.get(req.tenant, 0) >= quota.max_in_flight:
+                self.stats["rejected"] += 1
+                raise AdmissionError(req.tenant, f"max_in_flight="
+                                     f"{quota.max_in_flight} reached")
+            if (self._tenant_parked_bytes(req.tenant)
+                    >= quota.max_frontier_bytes):
+                self.stats["rejected"] += 1
+                raise AdmissionError(
+                    req.tenant,
+                    f"parked frontier bytes over "
+                    f"max_frontier_bytes={quota.max_frontier_bytes}")
+            sel = req.selectivity or self.server.default_selectivity
+            gdb = self.server._gdb_for(sel, req.seed)
+            output = "rows" if req.limit is not None else "count"
+            plan, _cached = self.server._plan_for(req, gdb, output=output)
+            budget = QuantumBudget(
+                None if self.policy == "fifo" else self.quantum_rows,
+                req.query_name, plan.gao, inner=plan.level_callback)
+            self._seq += 1
+            job = _Job(self._seq, req, plan, gdb, plan.engine, budget,
+                       collect_rows, self.vclock)
+            self._jobs.append(job)
+            self._queue.append(job)
+            self._in_flight[req.tenant] = (
+                self._in_flight.get(req.tenant, 0) + 1)
+            return job.token
 
     # -- parking -------------------------------------------------------------
     def _park(self, job: _Job, payload) -> None:
@@ -526,6 +530,8 @@ class QuantumScheduler:
                     stack.enter_context(job.profile.activate())
                     stack.enter_context(job.profile.attribute(
                         f"{job.token}/q{job.quanta}"))
+                stack.enter_context(span("scheduler.quantum", job=job.id,
+                                         quantum=job.quanta))
                 done = self._advance(job)
         except Preempted as p:
             job.preemptions += 1

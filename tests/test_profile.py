@@ -7,9 +7,8 @@ The contract under test, in tiers:
   identical to a run that never heard of profiling; same discipline as
   the PR 8 tracer guard.
 * **Faithful when on** — an active profile sees every kernel dispatch
-  (calls match the engine's own meters), buckets wall into the known
-  kernel families, samples live-buffer memory at level boundaries, and
-  publishes into the trace/metrics surfaces.
+  (calls match the engine's own meters), samples live-buffer memory at
+  level boundaries, and publishes into the trace/metrics surfaces.
 * **Attribution** — scheduler quanta label AOT compiles
   (``sched-<job>/q<k>``), the pool records per-worker spans, the server
   stamps one ``trace_id`` through the request log, trace, and profile.
@@ -30,8 +29,8 @@ import time
 import pytest
 
 from repro.core import GraphStats, count, execute_stats, get_query, plan_query
-from repro.obs import (KERNEL_FAMILIES, DeviceProfile, MetricsRegistry,
-                       NullProfile, QueryTrace, current_profile)
+from repro.obs import (DeviceProfile, MetricsRegistry, NullProfile,
+                       QueryTrace, current_profile)
 from repro.graphs import powerlaw_cluster
 from repro.serve import QuantumScheduler, QueryRequest, QueryServer
 
@@ -64,7 +63,6 @@ def test_null_profile_is_inert():
     n = NullProfile()
     n.record_jit_call()
     n.record_compile("k", 1.0)
-    n.record_kernel("intersect", 1.0)
     n.sample_memory()
     with n.activate():
         assert current_profile() is None       # never installed
@@ -118,12 +116,6 @@ def test_profile_harvests_kernels_and_memory(gdb):
     # every chunk/final dispatch the engine metered is a recorded call
     assert prof.jit["calls"] == stats["raw"]["chunks"] \
         + stats["raw"]["ll_calls"]
-    assert set(prof.kernels) <= set(KERNEL_FAMILIES)
-    assert "intersect" in prof.kernels
-    assert prof.kernels["intersect"]["calls"] >= 1
-    assert prof.kernel_wall_s() > 0.0
-    assert prof.kernel_wall_s("intersect") > 0.0
-    assert prof.kernel_wall_s("nope") == 0.0
     # memory watermark sampled at level boundaries, metadata only
     assert prof.memory["samples"] >= 1
     assert prof.memory["peak_live_bytes"] > 0
@@ -132,20 +124,6 @@ def test_profile_harvests_kernels_and_memory(gdb):
     d = json.loads(json.dumps(prof.to_dict()))
     assert d["meta"]["query"] == "3-clique"
     assert d["jit"]["calls"] == prof.jit["calls"]
-
-
-def test_profile_segment_outer_on_rows_path():
-    """Row enumeration goes through the cursor's segment_expand — the
-    third kernel family shows up only on the rows path."""
-    csr = powerlaw_cluster(n=200, m_per_node=3, seed=1)
-    server = QueryServer(csr)
-    prof = DeviceProfile("3-path", "vlftj")
-    with prof.activate():
-        res = server.execute(QueryRequest("3-path", engine="vlftj",
-                                          limit=200))
-    assert res.count > 0
-    assert "segment_outer" in prof.kernels
-    assert prof.kernels["segment_outer"]["calls"] >= 1
 
 
 def test_profile_publish_into_trace_and_registry(gdb):
@@ -159,12 +137,10 @@ def test_profile_publish_into_trace_and_registry(gdb):
     prof.publish(trace=tr, registry=reg)
     names = [s["name"] for s in tr.spans]
     assert "profile/jit" in names
-    assert any(n.startswith("profile/kernel/") for n in names)
     assert tr.summary["peak_live_bytes"] == prof.memory["peak_live_bytes"]
     snap = reg.snapshot()
     assert snap["profile_jit_calls"] == prof.jit["calls"]
     assert snap["profile_peak_live_bytes"] == prof.memory["peak_live_bytes"]
-    assert snap["profile_kernel_seconds_count{family=intersect}"] == 1
 
 
 # ---------------------------------------------------------------------------
