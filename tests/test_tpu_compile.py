@@ -19,6 +19,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.plan import MIN_WIDTH, executor_geometry
 from repro.core.vlftj import _expand_level
+from repro.core.yannakakis import _spmv
 from repro.kernels import ops as kops
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.intersect_bitset import (bitset_intersect_count_pallas,
@@ -87,6 +88,17 @@ def test_expand_level_compiles(one_chip, check_mode, count_only, width):
         s((chunk,), jnp.bool_), **bitset).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2 << 30
+
+
+def test_spmv_compiles_without_a_scatter(one_chip):
+    """Counting Yannakakis' SpMV over the whole edge array, with int64
+    messages: the TPU's compiler keeps it free of scatters."""
+    s = lambda shape, dt: _spec(one_chip, shape, dt)  # noqa: E731
+    compiled = _spmv.lower(
+        s((N_NODES + 1,), jnp.int32), s((N_EDGES,), jnp.int32),
+        s((N_EDGES,), jnp.int32), s((N_NODES,), jnp.int64),
+        num_segments=N_NODES).compile()
+    assert " scatter(" not in compiled.as_text()
 
 
 def test_bitset_intersect_kernel_compiles(one_chip):
